@@ -32,6 +32,7 @@ def phase(mesh_shape, steps, expect_start, store_factory=None,
     from repro.configs import get_smoke_config
     from repro.configs.base import ShapeConfig
     from repro.distrib.rules import rules_for
+    from repro.launch.mesh import make_debug_mesh
     from repro.models.api import build_model
     from repro.train.data import SyntheticLM
     from repro.train.loop import Trainer, TrainerConfig
@@ -41,7 +42,7 @@ def phase(mesh_shape, steps, expect_start, store_factory=None,
 
     cfg = get_smoke_config("qwen3_1_7b")
     api = build_model(cfg)
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_debug_mesh(*mesh_shape)
     rules = rules_for(cfg.arch)
     shape = ShapeConfig("ex", 32, 8, "train")
     opt = make_optimizer(cfg.optimizer)

@@ -29,6 +29,7 @@ def train_phase():
     from repro.configs import get_smoke_config
     from repro.configs.base import ShapeConfig
     from repro.distrib.rules import rules_for
+    from repro.launch.mesh import make_debug_mesh
     from repro.models.api import build_model
     from repro.train.data import SyntheticLM
     from repro.train.loop import Trainer, TrainerConfig
@@ -38,7 +39,7 @@ def train_phase():
 
     cfg = get_smoke_config("smollm_135m")
     api = build_model(cfg)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_debug_mesh(4, 2)
     rules = rules_for(cfg.arch)
     shape = ShapeConfig("pp", 32, 8, "train")
     opt = make_optimizer(cfg.optimizer)

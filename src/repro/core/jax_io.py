@@ -27,30 +27,12 @@ from repro.core.tensor_ckpt import ArrayShard, PerRankState, TensorCheckpoint
 _INT = np.int64
 
 
-def _simple_keystr(path) -> str:
-    """The "/"-joined simple form of a key path.
-
-    ``jax.tree_util.keystr(path, simple=True, separator="/")`` only exists on
-    jax >= 0.4.35's successors; on jax 0.4.x the kwargs raise ``TypeError``,
-    so build the string from the key entries directly (DictKey ``.key``,
-    SequenceKey ``.idx``, GetAttrKey/FlattenedIndexKey ``.name``/``.key``)."""
-    parts = []
-    for entry in path:
-        for attr in ("key", "idx", "name"):
-            if hasattr(entry, attr):
-                parts.append(str(getattr(entry, attr)))
-                break
-        else:
-            parts.append(str(entry))
-    return "/".join(parts)
-
-
 def tree_names(tree: Any) -> tuple[list[str], list[Any], Any]:
     """Stable path-derived names for every leaf + leaves + treedef."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
     names, leaves = [], []
     for path, leaf in flat:
-        names.append(_simple_keystr(path))
+        names.append(jax.tree_util.keystr(path, simple=True, separator="/"))
         leaves.append(leaf)
     assert len(set(names)) == len(names)
     return names, leaves, treedef

@@ -53,21 +53,13 @@ def mesh_context() -> MeshContext | None:
 
 
 @contextlib.contextmanager
-def use_mesh_context(ctx: MeshContext, *, set_jax_mesh: bool = False):
-    """Install the thread-local context.  ``set_jax_mesh`` additionally
-    sets JAX's ambient mesh — only safe OUTSIDE a trace; step builders
-    enter the plain context inside their traced bodies instead (model
-    code passes ``ctx.mesh`` to shard_map explicitly)."""
+def use_mesh_context(ctx: MeshContext):
+    """Install the thread-local context.  Step builders enter it inside
+    their traced bodies; model code passes ``ctx.mesh`` to shard_map
+    explicitly, so JAX's ambient mesh is never needed."""
     prev = getattr(_STATE, "ctx", None)
     _STATE.ctx = ctx
     try:
-        if set_jax_mesh:
-            # jax >= 0.6: jax.set_mesh(mesh); jax 0.4.x: the Mesh object is
-            # itself the ambient-mesh context manager
-            setter = getattr(jax, "set_mesh", None)
-            with (setter(ctx.mesh) if setter is not None else ctx.mesh):
-                yield ctx
-        else:
-            yield ctx
+        yield ctx
     finally:
         _STATE.ctx = prev

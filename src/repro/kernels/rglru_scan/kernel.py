@@ -7,10 +7,14 @@ loop, which XLA otherwise lowers to an O(log S) associative scan with
 S*log(S) HBM traffic).
 
 Tiling: grid (B, num_W_blocks, num_S_blocks); the time axis is the
-minormost (sequential) grid dim, so the carry h [1, bw] lives in VMEM
+minormost (sequential) grid dim, so the carry h [bw] lives in VMEM
 scratch across time blocks.  Within a block a fori_loop steps through
 ``block_s`` time steps of [bw]-wide vector ops — pure VPU work on lanes,
-W-blocked to the 128-lane register width.
+W-blocked to the 128-lane register width.  Each step loads its row from
+the refs (``a_ref[0, t, :]``): Mosaic lowers a dynamic row index on a ref,
+but not a dynamic slice of a value already loaded.  ``h0`` enters as
+[B, 1, W] so its (1, 1, bw) block spans the array's full second-minor
+dim, which the TPU tiling rule accepts for any B.
 
 Per-step VMEM: a, b tiles (2 * bs * bw f32) + carry (bw f32): with
 bs=256, bw=512 that is ~1 MiB.
@@ -34,13 +38,10 @@ def _scan_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, block_s: int):
 
     @pl.when(it == 0)
     def _init():
-        carry_ref[...] = h0_ref[0]
-
-    a = a_ref[0]                                   # [bs, bw] f32
-    b = b_ref[0]
+        carry_ref[...] = h0_ref[0, 0]
 
     def step(t, h):
-        h = a[t] * h + b[t]
+        h = a_ref[0, t, :] * h + b_ref[0, t, :]
         o_ref[0, t, :] = h.astype(o_ref.dtype)
         return h
 
@@ -77,7 +78,7 @@ def rglru_scan(a, b, h0=None, *, block_s: int = DEFAULT_BLOCK_S,
                          lambda ib, iw, it: (ib, it, iw)),
             pl.BlockSpec((1, block_s, block_w),
                          lambda ib, iw, it: (ib, it, iw)),
-            pl.BlockSpec((1, block_w), lambda ib, iw, it: (ib, iw)),
+            pl.BlockSpec((1, 1, block_w), lambda ib, iw, it: (ib, 0, iw)),
         ],
         out_specs=pl.BlockSpec((1, block_s, block_w),
                                lambda ib, iw, it: (ib, it, iw)),
@@ -85,6 +86,6 @@ def rglru_scan(a, b, h0=None, *, block_s: int = DEFAULT_BLOCK_S,
                                        a.dtype),
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
         interpret=interpret,
-    )(a, b, h0)
+    )(a, b, h0[:, None, :])
     h = out[:, :S, :W]
     return h, h[:, -1]

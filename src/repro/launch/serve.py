@@ -17,6 +17,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ShapeConfig
 from repro.distrib.rules import rules_for
+from repro.launch.compile_cache import init_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models.api import build_model, make_token_batch
 from repro.train.step import make_decode_step, make_prefill_step
@@ -33,6 +34,7 @@ def main(argv=None):
     ap.add_argument("--model-mesh", type=int, default=1)
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     api = build_model(cfg)

@@ -22,6 +22,7 @@ import jax
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ShapeConfig
 from repro.distrib.rules import rules_for
+from repro.launch.compile_cache import init_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models.api import build_model
 from repro.train.data import SyntheticLM
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--fail-at", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     api = build_model(cfg)

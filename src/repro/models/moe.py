@@ -32,29 +32,6 @@ from jax.sharding import PartitionSpec as P
 F32 = jnp.float32
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.6 exposes it at the top level with VMA typing; jax 0.4.x only
-    has ``jax.experimental.shard_map.shard_map``, whose replication checker
-    cannot type the sort/scatter dispatch below — there we disable
-    ``check_rep`` (the psum/out_specs contract is exercised directly by
-    tests/helpers/moe_ep_check.py against the dense oracle)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def _pvary(x, axis_names):
-    """``jax.lax.pvary`` where it exists (jax >= 0.7 VMA typing); identity on
-    older jax, which has no varying-manual-axes type system to inform."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axis_names) if fn is not None else x
-
-
 def moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
             capacity_factor: float = 1.25, num_real: int | None = None):
     """x [B, S, D]; router_w [D, E]; experts w_gate/w_up [E, D, F],
@@ -149,12 +126,13 @@ def _ep_body(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     key = jnp.where(mine, local_e, E_loc).astype(jnp.int32)       # E_loc = trash
 
     # ---- position-in-expert via sort (deterministic, cone-stable order) -
-    # NB: the val operand must be explicitly pvary'd over ep_axis.  With an
-    # invariant val, jax 0.8's VMA typing marks the returned permutation
+    # NB: the val operand must be explicitly pcast to varying over ep_axis.
+    # With an invariant val, JAX's VMA typing marks the returned permutation
     # invariant even though the (varying) key makes it shard-dependent, and
     # the shard_map transpose then miscomputes gradients (validated by
     # tests/helpers/moe_ep_check.py; forward is unaffected).
-    arange_v = _pvary(jnp.arange(T * top_k, dtype=jnp.int32), (ep_axis,))
+    arange_v = jax.lax.pcast(jnp.arange(T * top_k, dtype=jnp.int32),
+                             (ep_axis,), to="varying")
     key_s, perm = jax.lax.sort_key_val(key, arange_v)
     counts = jnp.bincount(key_s, length=E_loc + 1)
     starts = jnp.concatenate([jnp.zeros(1, counts.dtype),
@@ -227,8 +205,8 @@ def moe_ffn_ep(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     body = functools.partial(
         _ep_body, top_k=top_k, capacity=capacity, num_real=num_real,
         num_experts=E, ep_axis=ep_axis, fsdp_axis=fsdp, dp_axes=dp_axes)
-    fn = _shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(dp_axes, None, None), P(None, None),
                   w_spec_gu, w_spec_gu, w_spec_d),
         out_specs=(P(dp_axes, None, None), P()),

@@ -78,8 +78,12 @@ class Trainer:
         except FileNotFoundError:
             steps = []
         if not steps:
-            state = self.init_state_fn()
-            return state, 0
+            # built under jit straight onto the step's shardings: not all on
+            # the first device, and in the same layout as every later step
+            # input (another layout would compile the step a second time)
+            init = jax.jit(self.init_state_fn,
+                           out_shardings=self.step.state_shardings)
+            return init(), 0
         return self.restore_from(steps[-1])
 
     def restore_from(self, step: int) -> tuple[dict, int]:

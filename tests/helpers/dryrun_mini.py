@@ -12,6 +12,7 @@ import jax
 from repro.configs import get_smoke_config
 from repro.configs.base import ShapeConfig
 from repro.distrib.rules import rules_for
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.hlo_analysis import analyze_compiled
 from repro.models.api import build_model
 from repro.train.optim import make_optimizer
@@ -22,7 +23,7 @@ import functools
 
 def main():
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_debug_mesh(2, 4)
 
     # train step: gemma2 family (local/global windows, softcaps)
     cfg = get_smoke_config("gemma2_2b")

@@ -13,18 +13,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_debug_mesh
 from repro.models.moe import moe_ffn, moe_ffn_ep
-
-
-def _ambient_mesh(mesh):
-    """jax>=0.6 ``jax.set_mesh`` / jax 0.4.x Mesh-as-context-manager."""
-    setter = getattr(jax, "set_mesh", None)
-    return setter(mesh) if setter is not None else mesh
 
 
 def main():
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_debug_mesh(2, 4)
     B, S, D, E, F, K = 4, 16, 32, 8, 16, 2
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(B, S, D)), jnp.float32)
@@ -48,7 +43,7 @@ def main():
                             fsdp_axis="data")
         return y, aux
 
-    with _ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
         wgs = jax.device_put(wg, NamedSharding(mesh, P("model", "data", None)))
         wus = jax.device_put(wu, NamedSharding(mesh, P("model", "data", None)))
@@ -85,7 +80,7 @@ def main():
     wg_p = jnp.pad(wg, ((0, E_pad - E), (0, 0), (0, 0)))
     wu_p = jnp.pad(wu, ((0, E_pad - E), (0, 0), (0, 0)))
     wd_p = jnp.pad(wd, ((0, E_pad - E), (0, 0), (0, 0)))
-    with _ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_pad, aux_pad = jax.jit(
             lambda x: moe_ffn_ep(x, router_p, wg_p, wu_p, wd_p, top_k=K,
                                  capacity_factor=CF, num_real=E, mesh=mesh,

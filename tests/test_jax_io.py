@@ -29,6 +29,50 @@ def test_tree_names_stable():
                      "step"]
 
 
+def _joined_key_entries(path) -> str:
+    """Reference leaf name: each key entry's own key, index or attribute
+    name, "/"-joined — the names every stored checkpoint was written with."""
+    parts = []
+    for entry in path:
+        for attr in ("key", "idx", "name"):
+            if hasattr(entry, attr):
+                parts.append(str(getattr(entry, attr)))
+                break
+        else:
+            parts.append(str(entry))
+    return "/".join(parts)
+
+
+class _Pair(tuple):
+    """A custom pytree node: its children get FlattenedIndexKey paths."""
+
+
+jax.tree_util.register_pytree_node(
+    _Pair, lambda p: (list(p), None), lambda _, xs: _Pair(xs))
+
+
+def test_tree_names_match_key_entry_join():
+    """Leaf names are byte-identical to the key-entry join for every key
+    kind: DictKey (str and int keys), SequenceKey, GetAttrKey and
+    FlattenedIndexKey."""
+    import collections
+
+    Opt = collections.namedtuple("Opt", ["mu", "nu"])
+    one = jnp.ones((2,), jnp.float32)
+    tree = {
+        "params/wq": one,
+        "opt": Opt(mu={"w": one, "b": one}, nu=[one, (one, None, one)]),
+        "layers": {7: _Pair([one, {"deep": [one]}]), 10: one},
+    }
+    names, leaves, _ = tree_names(tree)
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    assert names == [_joined_key_entries(p) for p, _ in flat]
+    assert names == ["layers/7/0", "layers/7/1/deep/0", "layers/10",
+                     "opt/mu/b", "opt/mu/w", "opt/nu/0", "opt/nu/1/0",
+                     "opt/nu/1/2", "params/wq"]
+    assert len(leaves) == len(names)
+
+
 def test_jax_roundtrip(tmp_path):
     tree = _tree()
     store = DatasetStore(str(tmp_path), "w")
