@@ -1,0 +1,7 @@
+"""Mean seconds of ``FEMCheckpoint.load_function`` per restart."""
+
+from benchmarks.chip.harness import mean
+
+
+def read(rec):
+    return mean(rec.span_seconds("fe.load_function"))
