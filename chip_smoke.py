@@ -121,23 +121,22 @@ class Model:
         return {k: jax.device_put(v, self.step.batch_shardings[k])
                 for k, v in self.data.batch(i).items()}
 
-    def trainer(self, ckpt_dir: str, save_seconds: list | None = None):
+    def trainer(self, ckpt_dir: str):
         from repro.train.loop import Trainer, TrainerConfig
 
-        tr = Trainer(self.step, self.data,
-                     TrainerConfig(ckpt_dir=ckpt_dir, ckpt_every=CKPT_EVERY,
-                                   async_ckpt=True, log_every=1),
-                     init_state_fn=self.init_state)
-        if save_seconds is not None:
-            save = tr._save
+        return Trainer(self.step, self.data,
+                       TrainerConfig(ckpt_dir=ckpt_dir, ckpt_every=CKPT_EVERY,
+                                     async_ckpt=True, log_every=1),
+                       init_state_fn=self.init_state)
 
-            def timed_save(state, step_idx):
-                t0 = time.perf_counter()
-                save(state, step_idx)
-                save_seconds.append((step_idx, time.perf_counter() - t0))
 
-            tr._save = timed_save
-        return tr
+def saves_since(t0: float) -> list[tuple[int, float]]:
+    """(step, seconds) of each save that blocked the loop since ``t0``:
+    the program's ``ckpt.save`` spans."""
+    from repro.core import spans
+
+    return [(s.attrs["step"], s.seconds) for s in spans.spans()
+            if s.name == "ckpt.save" and s.t0 >= t0]
 
 
 def losses(history: list[dict], first: int, last: int) -> list[float]:
@@ -171,10 +170,10 @@ def one_chip(workdir: Path) -> None:
 
     model = Model(make_debug_mesh(1, 1))
     time_steps(model, str(workdir / "cold"))
-    saves: list = []
+    t_saves = time.perf_counter()
 
     # ---- run A: straight to step 6
-    ta = model.trainer(str(workdir / "a"), saves)
+    ta = model.trainer(str(workdir / "a"))
     ra = ta.run(3)
     check(ra["saved_steps"] == [3], "run A saved step 3")
     h3 = jax.device_get(ra["state"])
@@ -187,7 +186,7 @@ def one_chip(workdir: Path) -> None:
 
     # ---- run B: killed at step 5, resumed from committed step 3
     tb_dir = str(workdir / "b")
-    tb = model.trainer(tb_dir, saves)
+    tb = model.trainer(tb_dir)
     try:
         tb.run(6, fail_at=5)
     except SimulatedPreemption:
@@ -197,7 +196,7 @@ def one_chip(workdir: Path) -> None:
     ck = TensorCheckpoint(DatasetStore(tb_dir, "r"))
     check(ck.steps() == [3], f"run B committed steps {ck.steps()} == [3]")
 
-    tr = model.trainer(tb_dir, saves)
+    tr = model.trainer(tb_dir)
     t0 = time.perf_counter()
     state, start = tr.restore_latest()
     jax.block_until_ready(state)
@@ -213,7 +212,7 @@ def one_chip(workdir: Path) -> None:
     check(losses_b == losses_a[3:],
           f"resumed losses of steps 4-6 {losses_b} == run A's")
 
-    for step_idx, seconds in saves:
+    for step_idx, seconds in saves_since(t_saves):
         reading(f"save_blocked_loop_seconds[step {step_idx}]", seconds)
     reading("restore_seconds[step 3]", restore_s)
 
@@ -224,15 +223,16 @@ def four_chips(workdir: Path) -> None:
     devices = jax.devices()
     check(len(devices) == 4, f"{len(devices)} devices == 4")
     ckpt_dir = str(workdir / "four")
-    saves: list = []
 
     src = Model(make_debug_mesh(4, 1))
-    tr = src.trainer(ckpt_dir, saves)
+    tr = src.trainer(ckpt_dir)
+    t_saves = time.perf_counter()
     res = tr.run(3)
     check(res["saved_steps"] == [3], "(4, 1) mesh saved step 3")
     assert_shardings(res["state"], src.step, "(4, 1) state after 3 steps")
     h3 = jax.device_get(res["state"])
-    reading("save_blocked_loop_seconds[step 3, (4, 1) mesh]", saves[0][1])
+    reading("save_blocked_loop_seconds[step 3, (4, 1) mesh]",
+            saves_since(t_saves)[0][1])
 
     for shape, devs in (((2, 2), None), ((2, 1), devices[:2])):
         dst = Model(make_debug_mesh(*shape, devices=devs))

@@ -77,7 +77,6 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
-import time
 import traceback
 from typing import Callable
 
@@ -85,6 +84,7 @@ import numpy as np
 
 from repro.analysis import hot_path
 from repro.core.comm import Comm
+from repro.core.spans import span
 from repro.core.store import COMMIT_LOG_KEY, DEFAULT_SERIES, DatasetStore
 from repro.core.tensor_ckpt import ArrayShard, PerRankState, TensorCheckpoint
 
@@ -138,17 +138,17 @@ class StagingArena:
                 f"budget or shrink the checkpointed state")
         with self._cond:
             self.stats.acquires += 1
-            t0 = time.perf_counter()
             waited = False
-            while not (self._free
-                       and (self.budget_bytes is None
-                            or self._live_bytes + nbytes
-                            <= self.budget_bytes)):
-                waited = True
-                self._cond.wait()
+            with span("ckpt.stage.wait") as sp:
+                while not (self._free
+                           and (self.budget_bytes is None
+                                or self._live_bytes + nbytes
+                                <= self.budget_bytes)):
+                    waited = True
+                    self._cond.wait()
             if waited:
                 self.stats.backpressure_hits += 1
-                self.stats.blocked_seconds += time.perf_counter() - t0
+                self.stats.blocked_seconds += sp.seconds
             slot = self._free.pop()
             slab = self._slabs[slot]
             if slab is None or slab.size < nbytes:
@@ -239,6 +239,7 @@ class _Job:
     label: str
     commit: dict | None = None         # commit-log entry, written LAST
     step: int | None = None            # tensor step (completed_steps)
+    series_step: int | None = None     # series step, the job span's step
 
 
 class AsyncCheckpointer:
@@ -281,7 +282,7 @@ class AsyncCheckpointer:
         self.arena = StagingArena(staging_budget_bytes)
         self.completed_steps: list[int] = []
         self.job_log: list[dict] = []    # {"label", "t0", "t1", "seconds"}
-        self._series_label = "?"         # last begin_step, for job labels
+        self._series_step: int | None = None   # last begin_step
         # test hook: raised inside the writer thread to simulate a crash
         self.fail_on_step: int | None = None
         self._queue: queue.Queue[_Job] = queue.Queue()
@@ -301,9 +302,12 @@ class AsyncCheckpointer:
     def submit(self, per_rank: PerRankState, step: int) -> None:
         """Snapshot tensor state synchronously, write asynchronously."""
         self._raise_pending()              # writer errors surface here too
-        slot = self.arena.acquire(_state_nbytes(per_rank))
+        nbytes = _state_nbytes(per_rank)
+        slot = self.arena.acquire(nbytes)
         try:
-            snap = _snapshot(per_rank, self.arena.buffer(slot))
+            with span("ckpt.stage.pack", step=int(step), bytes=nbytes,
+                      pass_=True):
+                snap = _snapshot(per_rank, self.arena.buffer(slot))
         except BaseException:
             self.arena.release(slot)
             raise
@@ -315,7 +319,7 @@ class AsyncCheckpointer:
 
         self._enqueue(_Job(run, slot, f"state/s{step}",
                            commit={"kind": "state", "step": int(step)},
-                           step=int(step)))
+                           step=int(step), series_step=int(step)))
 
     def save_mesh(self, name: str, plexes: list, comm: Comm | None = None,
                   labels: dict[str, list[np.ndarray]] | None = None) -> None:
@@ -382,14 +386,15 @@ class AsyncCheckpointer:
         """Open series step ``step`` (ordered on the writer thread): every
         save queued until ``commit_step`` stages into the step."""
         self._raise_pending()
-        self._series_label = f"s{int(step)}"
+        self._series_step = int(step)
 
         def run(step=int(step)):
             # the matching commit_step is its own queued writer job, so the
             # open step intentionally outlives this job's function scope
             self.store.begin_step(step, series)  # ckptlint: disable=CKPT007
 
-        self._enqueue(_Job(run, None, f"begin/{self._series_label}"))
+        self._enqueue(_Job(run, None, f"begin/s{self._series_step}",
+                           series_step=self._series_step))
 
     def commit_step(self) -> None:
         """Commit the open series step — the job's ONLY write is the single
@@ -397,8 +402,9 @@ class AsyncCheckpointer:
         step failed, the writer skips this job too and the step stays
         invisible (torn), exactly like a crash."""
         self._raise_pending()
-        self._enqueue(_Job(self.store.commit_step, None,
-                           f"commit/{self._series_label}"))
+        label = "?" if self._series_step is None else f"s{self._series_step}"
+        self._enqueue(_Job(self.store.commit_step, None, f"commit/{label}",
+                           series_step=self._series_step))
 
     def wait(self) -> None:
         """Drain every submitted job; re-raise the first writer failure."""
@@ -433,15 +439,15 @@ class AsyncCheckpointer:
                 with self._lock:
                     failed = self._error is not None
                 if not failed:
-                    t0 = time.perf_counter()
-                    job.run()
-                    if job.commit is not None:
-                        _append_commit(self.store, job.commit)
-                    t1 = time.perf_counter()
+                    with span("ckpt.writer.job", label=job.label,
+                              step=job.series_step) as sp:
+                        job.run()
+                        if job.commit is not None:
+                            _append_commit(self.store, job.commit)
                     with self._lock:
                         self.job_log.append(
-                            {"label": job.label, "t0": t0,
-                             "t1": t1, "seconds": t1 - t0})
+                            {"label": job.label, "t0": sp.t0,
+                             "t1": sp.t1, "seconds": sp.seconds})
                         if job.step is not None:
                             self.completed_steps.append(job.step)
             except BaseException as e:   # noqa: BLE001 — surfaced on submit/wait

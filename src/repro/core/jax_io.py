@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.core.chunk_layout import ArraySpec, Box, StateLayout
 from repro.core.comm import Comm
+from repro.core.spans import span
 from repro.core.store import np_dtype
 from repro.core.tensor_ckpt import ArrayShard, PerRankState, TensorCheckpoint
 
@@ -94,28 +95,38 @@ def snapshot_jax(layout, tree: Any) -> PerRankState:
     """Device -> host snapshot of this process's owned chunks.
 
     The returned numpy blocks are COPIES (safe against buffer donation
-    by the next step while an async write is in flight)."""
+    by the next step while an async write is in flight).  Per leaf, the
+    blocking device -> host fetch of its shards (``ckpt.snapshot.d2h``)
+    and the per-chunk host copies (``ckpt.snapshot.copy``) are spans."""
     names, leaves, _ = tree_names(tree)
     rank_state: dict[str, ArrayShard] = {}
-    for name, leaf in zip(names, leaves):
-        spec = layout.spec(name)
-        grid = spec.grid
-        data: dict[int, np.ndarray] = {}
-        for shard in leaf.addressable_shards:
-            if shard.replica_id != 0:
-                continue                        # ghost (paper §2.1.1)
-            box = _box_from_index(shard.index, spec.shape)
-            ords = grid.chunks_intersecting(box)
-            block = np.asarray(shard.data)
-            for o in ords:
-                cbox = grid.chunk_box(o)
-                assert box.contains(cbox), (
-                    f"{name}: shard box {box} does not tile chunk {cbox}")
-                data[o] = np.array(block[cbox.slices(origin=box)],
-                                   copy=True, order="C")
-        if data:
-            ords = np.array(sorted(data), dtype=_INT)
-            rank_state[name] = ArrayShard(ords, data)
+    with span("ckpt.snapshot") as snap:
+        for name, leaf in zip(names, leaves):
+            spec = layout.spec(name)
+            grid = spec.grid
+            with span("ckpt.snapshot.d2h") as d2h:
+                owned = [(_box_from_index(shard.index, spec.shape),
+                          np.asarray(shard.data))
+                         for shard in leaf.addressable_shards
+                         if shard.replica_id == 0]   # ghosts: paper §2.1.1
+                d2h.attrs["bytes"] = sum(b.nbytes for _, b in owned)
+            data: dict[int, np.ndarray] = {}
+            with span("ckpt.snapshot.copy", pass_=True) as copy:
+                for box, block in owned:
+                    for o in grid.chunks_intersecting(box):
+                        cbox = grid.chunk_box(o)
+                        assert box.contains(cbox), (
+                            f"{name}: shard box {box} does not tile chunk "
+                            f"{cbox}")
+                        data[o] = np.array(block[cbox.slices(origin=box)],
+                                           copy=True, order="C")
+                copy.attrs["bytes"] = sum(b.nbytes for b in data.values())
+                owned = block = None     # the fetched shards are freed here
+            if data:
+                ords = np.array(sorted(data), dtype=_INT)
+                rank_state[name] = ArrayShard(ords, data)
+        snap.attrs["bytes"] = sum(b.nbytes for sh in rank_state.values()
+                                  for b in sh.data.values())
     return [rank_state]                         # one rank per process
 
 
@@ -129,31 +140,37 @@ def save_jax(ck: TensorCheckpoint, tree: Any, step: int) -> None:
 
 def load_jax(ck: TensorCheckpoint, target: Any, step: int) -> Any:
     """Load into a pytree of ``jax.ShapeDtypeStruct`` (with ``.sharding``) or
-    arrays; returns a pytree of committed jax Arrays on the target sharding."""
+    arrays; returns a pytree of committed jax Arrays on the target sharding.
+    The arrays are built on the devices under ``ckpt.load.h2d``, which
+    times the transfers' issue: nothing here waits for them to land."""
     names, leaves, treedef = tree_names(target)
-    plan_rank: dict[str, list[Box]] = {}
-    for name, leaf in zip(names, leaves):
-        shape = tuple(int(s) for s in leaf.shape)
-        boxes: list[Box] = []
-        idx_map = leaf.sharding.addressable_devices_indices_map(shape)
-        for index in idx_map.values():
-            b = _box_from_index(index, shape)
-            if b not in boxes:
-                boxes.append(b)
-        plan_rank[name] = boxes
-    out = ck.load_state([plan_rank], Comm(jax.process_count()), step)[0]
+    with span("ckpt.load") as load:
+        plan_rank: dict[str, list[Box]] = {}
+        for name, leaf in zip(names, leaves):
+            shape = tuple(int(s) for s in leaf.shape)
+            boxes: list[Box] = []
+            idx_map = leaf.sharding.addressable_devices_indices_map(shape)
+            for index in idx_map.values():
+                b = _box_from_index(index, shape)
+                if b not in boxes:
+                    boxes.append(b)
+            plan_rank[name] = boxes
+        out = ck.load_state([plan_rank], Comm(jax.process_count()), step)[0]
 
-    results = []
-    for name, leaf in zip(names, leaves):
-        shape = tuple(int(s) for s in leaf.shape)
-        lut = {(b.start, b.stop): arr
-               for b, arr in zip(plan_rank[name], out[name])}
+        results = []
+        with span("ckpt.load.h2d") as h2d:
+            for name, leaf in zip(names, leaves):
+                shape = tuple(int(s) for s in leaf.shape)
+                lut = {(b.start, b.stop): arr
+                       for b, arr in zip(plan_rank[name], out[name])}
 
-        def cb(index, _name=name, _shape=shape, _lut=lut, _leaf=leaf):
-            b = _box_from_index(index, _shape)
-            return np.asarray(_lut[(b.start, b.stop)],
-                              dtype=np_dtype(str(_leaf.dtype)))
+                def cb(index, _name=name, _shape=shape, _lut=lut, _leaf=leaf):
+                    b = _box_from_index(index, _shape)
+                    return np.asarray(_lut[(b.start, b.stop)],
+                                      dtype=np_dtype(str(_leaf.dtype)))
 
-        results.append(jax.make_array_from_callback(
-            shape, leaf.sharding, cb))
+                results.append(jax.make_array_from_callback(
+                    shape, leaf.sharding, cb))
+            h2d.attrs["bytes"] = sum(int(a.nbytes) for a in results)
+        load.attrs["bytes"] = h2d.attrs["bytes"]
     return jax.tree_util.tree_unflatten(treedef, results)

@@ -72,12 +72,12 @@ import dataclasses
 import hashlib
 import json
 import os
-import time
 from typing import Any
 
 import numpy as np
 
 from repro.analysis import hot_path
+from repro.core.spans import span
 
 #: attr key of the per-series step manifests (absent on legacy stores)
 SERIES_KEY = "series/manifest"
@@ -101,11 +101,15 @@ def content_hash(arrays, starts=None) -> str:
         else list(enumerate(arrays))
     pairs.sort(key=lambda p: int(p[0]))
     h = hashlib.blake2b(digest_size=16)
-    for start, a in pairs:
-        a = np.ascontiguousarray(a)
-        h.update(f"{int(start)}:{a.dtype}:{a.shape};".encode())
-        if a.size:
-            h.update(a.reshape(-1).view(np.uint8))
+    with span("ckpt.store.hash", pass_=True) as sp:
+        nbytes = 0
+        for start, a in pairs:
+            a = np.ascontiguousarray(a)
+            h.update(f"{int(start)}:{a.dtype}:{a.shape};".encode())
+            if a.size:
+                h.update(a.reshape(-1).view(np.uint8))
+                nbytes += a.nbytes
+        sp.attrs["bytes"] = nbytes
     return h.hexdigest()
 
 
@@ -186,9 +190,11 @@ class DatasetStore:
 
     def _flush_meta(self) -> None:
         tmp = self._meta_path() + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(self._meta, f, indent=1, sort_keys=True)
-        os.replace(tmp, self._meta_path())  # atomic commit
+        with span("ckpt.store.flush_meta") as sp:
+            with open(tmp, "w") as f:
+                json.dump(self._meta, f, indent=1, sort_keys=True)
+                sp.attrs["bytes"] = f.tell()
+            os.replace(tmp, self._meta_path())  # atomic commit
 
     def set_attrs(self, key: str, value: Any) -> None:
         if self.mode not in ("w", "a"):
@@ -327,15 +333,16 @@ class DatasetStore:
         crash anywhere before this call leaves the step invisible.
         """
         p = self._require_pending()
-        series = self._meta["attrs"].setdefault(SERIES_KEY, {})
-        man = series.setdefault(p["series"], {"steps": {}, "hashes": {}})
-        man["steps"][str(p["step"])] = p["datasets"]
-        man["hashes"].update(p["new_hashes"])
-        self._meta["attrs"].update(p["attrs"])
-        # re-point: staged attrs must not resurrect a stale SERIES_KEY
-        self._meta["attrs"][SERIES_KEY] = series
-        self._pending = None
-        self._flush_meta()
+        with span("ckpt.commit", step=p["step"]):
+            series = self._meta["attrs"].setdefault(SERIES_KEY, {})
+            man = series.setdefault(p["series"], {"steps": {}, "hashes": {}})
+            man["steps"][str(p["step"])] = p["datasets"]
+            man["hashes"].update(p["new_hashes"])
+            self._meta["attrs"].update(p["attrs"])
+            # re-point: staged attrs must not resurrect a stale SERIES_KEY
+            self._meta["attrs"][SERIES_KEY] = series
+            self._pending = None
+            self._flush_meta()
 
     def abort_step(self) -> None:
         """Drop the open step.  Extents it created stay on disk as orphans
@@ -426,16 +433,16 @@ class DatasetStore:
                 f"{name}: write range [{start}, {start + data.shape[0]}) "
                 f"out of range for {info['rows']} rows")
         self._invalidate_reader(name)
-        t0 = time.perf_counter()
         buf_rows = self.buffer_rows or data.shape[0] or 1
-        with open(self._path(name), "r+b") as f:
+        with span("ckpt.store.write", bytes=data.nbytes, pass_=True) as sp, \
+                open(self._path(name), "r+b") as f:
             f.seek(start * rb)
             raw = data.tobytes()  # staging copy == bounce buffer
             step = buf_rows * rb
             for off in range(0, len(raw), step):
                 f.write(raw[off:off + step])
                 self.stats.write_calls += 1
-        self.stats.write_seconds += time.perf_counter() - t0
+        self.stats.write_seconds += sp.seconds
         self.stats.bytes_written += data.nbytes
 
     @hot_path
@@ -480,8 +487,8 @@ class DatasetStore:
                     f"{name}: overlapping write segments at row {b}")
         self._invalidate_reader(name)
         total = sum(d.nbytes for _, d in segs)
-        t0 = time.perf_counter()
-        with open(self._path(name), "r+b") as f:
+        with span("ckpt.store.write", bytes=total) as sp, \
+                open(self._path(name), "r+b") as f:
             i = 0
             while i < len(segs):
                 j, end = i + 1, segs[i][0] + segs[i][1].shape[0]
@@ -510,7 +517,7 @@ class DatasetStore:
                         off += n
                         slab_left -= n
                 i = j
-        self.stats.write_seconds += time.perf_counter() - t0
+        self.stats.write_seconds += sp.seconds
         self.stats.bytes_written += total
 
     @hot_path
@@ -530,17 +537,17 @@ class DatasetStore:
         self._invalidate_reader(name)
         order = np.argsort(row_idx, kind="stable")
         row_idx, data = row_idx[order], data[order]
-        t0 = time.perf_counter()
         # coalesce maximal contiguous runs (the loader-side optimisation of
         # §"straggler mitigation" applies to writes too)
         breaks = np.flatnonzero(np.diff(row_idx) != 1) + 1
         starts = np.concatenate([[0], breaks, [row_idx.size]])
-        with open(self._path(name), "r+b") as f:
+        with span("ckpt.store.write", bytes=data.nbytes) as sp, \
+                open(self._path(name), "r+b") as f:
             for a, b in zip(starts[:-1], starts[1:]):
                 f.seek(int(row_idx[a]) * rb)
                 f.write(data[a:b].tobytes())
                 self.stats.write_calls += 1
-        self.stats.write_seconds += time.perf_counter() - t0
+        self.stats.write_seconds += sp.seconds
         self.stats.bytes_written += data.nbytes
 
     # ---------------------------------------------------------------- reads
@@ -555,11 +562,12 @@ class DatasetStore:
         # readinto a preallocated buffer: one pass instead of the old
         # read -> frombuffer -> copy (two passes over 268 MiB reads)
         out = np.empty((count, *info["row_shape"]), dtype=np_dtype(info["dtype"]))
-        t0 = time.perf_counter()
-        f = self._reader(name)
-        f.seek(start * rb)
-        got = f.readinto(out.reshape(-1).view(np.uint8))
-        self.stats.read_seconds += time.perf_counter() - t0
+        with span("ckpt.load.read") as sp:
+            f = self._reader(name)
+            f.seek(start * rb)
+            got = f.readinto(out.reshape(-1).view(np.uint8))
+            sp.attrs["bytes"] = int(got)
+        self.stats.read_seconds += sp.seconds
         self.stats.read_calls += 1
         self.stats.bytes_read += int(got)
         if got != count * rb:
@@ -593,27 +601,30 @@ class DatasetStore:
                        key=lambda i: starts[i])
         out: list[np.ndarray] = [
             np.empty((c, *info["row_shape"]), dtype=dt) for c in counts]
-        t0 = time.perf_counter()
-        f = self._reader(name)
-        i = 0
-        while i < len(order):
-            j = i + 1
-            end = starts[order[i]] + counts[order[i]]
-            while j < len(order) and starts[order[j]] <= end:
-                end = max(end, starts[order[j]] + counts[order[j]])
-                j += 1
-            run_start = starts[order[i]]
-            f.seek(run_start * rb)
-            raw = f.read((end - run_start) * rb)
-            self.stats.read_calls += 1
-            self.stats.bytes_read += len(raw)
-            run = np.frombuffer(raw, dtype=dt).reshape(
-                (end - run_start, *info["row_shape"]))
-            for k in order[i:j]:
-                a = starts[k] - run_start
-                out[k][...] = run[a:a + counts[k]]
-            i = j
-        self.stats.read_seconds += time.perf_counter() - t0
+        with span("ckpt.load.read") as sp:
+            f = self._reader(name)
+            nbytes = 0
+            i = 0
+            while i < len(order):
+                j = i + 1
+                end = starts[order[i]] + counts[order[i]]
+                while j < len(order) and starts[order[j]] <= end:
+                    end = max(end, starts[order[j]] + counts[order[j]])
+                    j += 1
+                run_start = starts[order[i]]
+                f.seek(run_start * rb)
+                raw = f.read((end - run_start) * rb)
+                self.stats.read_calls += 1
+                nbytes += len(raw)
+                run = np.frombuffer(raw, dtype=dt).reshape(
+                    (end - run_start, *info["row_shape"]))
+                for k in order[i:j]:
+                    a = starts[k] - run_start
+                    out[k][...] = run[a:a + counts[k]]
+                i = j
+            sp.attrs["bytes"] = nbytes
+        self.stats.read_seconds += sp.seconds
+        self.stats.bytes_read += nbytes
         return out
 
     @hot_path
@@ -634,19 +645,22 @@ class DatasetStore:
         breaks = np.flatnonzero(np.diff(sorted_idx) != 1) + 1
         starts = np.concatenate([[0], breaks, [sorted_idx.size]])
         rb = self._row_nbytes(info)
-        t0 = time.perf_counter()
-        f = self._reader(name)
-        for a, b in zip(starts[:-1], starts[1:]):
-            # row index arrives id-scale from the closure loaders; mix the
-            # byte offset in uint64 so the product cannot wrap int64
-            f.seek(int(np.uint64(sorted_idx[a]) * np.uint64(rb)))
-            raw = f.read((b - a) * rb)
-            self.stats.read_calls += 1
-            self.stats.bytes_read += len(raw)
-            out[order[a:b]] = np.frombuffer(
-                raw, dtype=np_dtype(info["dtype"])
-            ).reshape((b - a, *info["row_shape"]))
-        self.stats.read_seconds += time.perf_counter() - t0
+        with span("ckpt.load.read") as sp:
+            f = self._reader(name)
+            nbytes = 0
+            for a, b in zip(starts[:-1], starts[1:]):
+                # row index arrives id-scale from the closure loaders; mix
+                # the byte offset in uint64 so the product cannot wrap int64
+                f.seek(int(np.uint64(sorted_idx[a]) * np.uint64(rb)))
+                raw = f.read((b - a) * rb)
+                self.stats.read_calls += 1
+                nbytes += len(raw)
+                out[order[a:b]] = np.frombuffer(
+                    raw, dtype=np_dtype(info["dtype"])
+                ).reshape((b - a, *info["row_shape"]))
+            sp.attrs["bytes"] = nbytes
+        self.stats.read_seconds += sp.seconds
+        self.stats.bytes_read += nbytes
         return out
 
 

@@ -35,6 +35,7 @@ from repro.core.chunk_layout import (
     ArraySpec, Box, StateLayout, plan_regions,
 )
 from repro.core.comm import Comm, split_segments
+from repro.core.spans import span
 from repro.core.star_forest import StarForest, partition_segments
 from repro.core.store import DatasetStore, np_dtype
 
@@ -241,10 +242,14 @@ class TensorCheckpoint:
         shards = [rs.get(name) for rs in per_rank]
         blocks = [np.ascontiguousarray(sh.data[int(o)]).reshape(-1)
                   for sh in shards if sh is not None for o in sh.ordinals]
-        vec_flat = (np.concatenate(blocks) if blocks
-                    else np.empty(0, dtype=np_dtype(spec.dtype)))
-        crc_flat = np.fromiter((zlib.crc32(b.tobytes()) for b in blocks),
-                               dtype=_INT, count=len(blocks))
+        with span("ckpt.write.concat", pass_=True) as sp:
+            vec_flat = (np.concatenate(blocks) if blocks
+                        else np.empty(0, dtype=np_dtype(spec.dtype)))
+            sp.attrs["bytes"] = vec_flat.nbytes
+        # two host passes: each block's ``tobytes`` copy, then crc32's scan
+        with span("ckpt.write.crc", bytes=2 * vec_flat.nbytes, pass_=True):
+            crc_flat = np.fromiter((zlib.crc32(b.tobytes()) for b in blocks),
+                                   dtype=_INT, count=len(blocks))
         st.staged_write(vec, spec.size, (), spec.dtype, d_base,
                         split_segments(vec_flat, sec["d_cnt"]))
         st.staged_write(crc, sec["Eo"], (), "int64", e_base,
@@ -294,27 +299,29 @@ class TensorCheckpoint:
                    step: int) -> list[dict[str, list[np.ndarray]]]:
         """``plan[rank][array] = [target Box, ...]`` -> same structure of
         filled numpy arrays.  Regions may cut across saved chunks freely."""
-        layout = self.layout()
-        meta = self.store.get_attrs("meta")
-        step_epochs = self._committed_epochs(meta, step)
-        M = comm.nranks
-        if len(plan) != M:
-            raise ValueError(
-                f"load_state: plan covers {len(plan)} ranks on a "
-                f"{M}-rank communicator")
-        out: list[dict[str, list[np.ndarray]]] = [dict() for _ in range(M)]
-        st = self._read_store(step)
-        for spec in layout.arrays:
-            regions = [p.get(spec.name, []) for p in plan]
-            if not any(regions):
-                continue
-            vals = self._load_array(spec, regions, comm,
-                                    int(step_epochs[spec.name]), step, meta,
-                                    st)
-            for slot, regs, v in zip(out, regions, vals):
-                if regs:
-                    slot[spec.name] = v
-        return out
+        with span("ckpt.load.state"):
+            layout = self.layout()
+            meta = self.store.get_attrs("meta")
+            step_epochs = self._committed_epochs(meta, step)
+            M = comm.nranks
+            if len(plan) != M:
+                raise ValueError(
+                    f"load_state: plan covers {len(plan)} ranks on a "
+                    f"{M}-rank communicator")
+            out: list[dict[str, list[np.ndarray]]] = [dict()
+                                                      for _ in range(M)]
+            st = self._read_store(step)
+            for spec in layout.arrays:
+                regions = [p.get(spec.name, []) for p in plan]
+                if not any(regions):
+                    continue
+                vals = self._load_array(spec, regions, comm,
+                                        int(step_epochs[spec.name]), step,
+                                        meta, st)
+                for slot, regs, v in zip(out, regions, vals):
+                    if regs:
+                        slot[spec.name] = v
+            return out
 
     @hot_path
     def _load_array(self, spec: ArraySpec, regions: list[list[Box]],
@@ -328,28 +335,37 @@ class TensorCheckpoint:
         vec = f"{key}/s{step}/vec"
 
         # ---- same-count fast path (§3.1): regions == saved chunks ----------
-        if M == sec["nranks"] and _plan_matches_saved(grid, regions, sec):
+        with span("ckpt.load.plan"):
+            fast = (M == sec["nranks"]
+                    and _plan_matches_saved(grid, regions, sec))
+        if fast:
             per_rank_rows = st.read_plan(vec, sec["d_base"], sec["d_cnt"])
-            e_cnt = np.asarray([len(o) for o in sec["ordinals_per_rank"]],
-                               dtype=_INT)
-            ords_flat = (np.concatenate(
-                [np.asarray(o, dtype=_INT)
-                 for o in sec["ordinals_per_rank"]])
-                if len(e_cnt) else np.empty(0, _INT))
-            cstart, cstop = grid.chunk_bounds(ords_flat)
-            shapes = cstop - cstart
-            csz = np.prod(shapes, axis=1, dtype=_INT)
-            # within-rank row offsets: rank-major global cumsum minus d_base
-            off = ((np.cumsum(csz) - csz)
-                   - np.repeat(np.asarray(sec["d_base"], dtype=_INT), e_cnt))
-            rank_rep = np.repeat(np.arange(M, dtype=_INT), e_cnt)
-            blocks = [per_rank_rows[r][a:a + s].reshape(tuple(map(int, shp)))
-                      for r, a, s, shp in zip(rank_rep, off, csz, shapes)]
-            bb = np.concatenate([[0], np.cumsum(e_cnt)]).astype(_INT)
-            return [blocks[a:b] for a, b in zip(bb[:-1], bb[1:])]
+            with span("ckpt.load.scatter"):
+                e_cnt = np.asarray(
+                    [len(o) for o in sec["ordinals_per_rank"]], dtype=_INT)
+                ords_flat = (np.concatenate(
+                    [np.asarray(o, dtype=_INT)
+                     for o in sec["ordinals_per_rank"]])
+                    if len(e_cnt) else np.empty(0, _INT))
+                cstart, cstop = grid.chunk_bounds(ords_flat)
+                shapes = cstop - cstart
+                csz = np.prod(shapes, axis=1, dtype=_INT)
+                # within-rank row offsets: rank-major global cumsum minus
+                # d_base
+                off = ((np.cumsum(csz) - csz)
+                       - np.repeat(np.asarray(sec["d_base"], dtype=_INT),
+                                   e_cnt))
+                rank_rep = np.repeat(np.arange(M, dtype=_INT), e_cnt)
+                blocks = [per_rank_rows[r][a:a + s].reshape(
+                    tuple(map(int, shp)))
+                    for r, a, s, shp in zip(rank_rep, off, csz, shapes)]
+                bb = np.concatenate([[0], np.cumsum(e_cnt)]).astype(_INT)
+                return [blocks[a:b] for a, b in zip(bb[:-1], bb[1:])]
 
         # ---- general path: ONE flat region plan, no per-rank walks ---------
-        rp = plan_regions(grid, regions)
+        with span("ckpt.load.plan") as sp:
+            rp = plan_regions(grid, regions)
+            sp.attrs["elements"] = int(rp.elem_within.size)
 
         # §2.2.5: canonical section chunks -> χ_{I_P}^{L_P}.  The canonical
         # segments tile [0, Eo), so one contiguous read IS the coalesced
@@ -358,36 +374,43 @@ class TensorCheckpoint:
         locG = st.read_rows(f"{key}/G", 0, Eo).astype(_INT, copy=False)
         locDOF = st.read_rows(f"{key}/DOF", 0, Eo).astype(_INT, copy=False)
         locOFF = st.read_rows(f"{key}/OFF", 0, Eo).astype(_INT, copy=False)
-        chi_IP_LP = StarForest.from_flat_global_numbers(
-            locG, en, grid.num_chunks, M)
-
-        # (2.17): χ_{I_T}^{I_P}
-        chi_IT_LP = StarForest.from_flat_global_numbers(
-            rp.needed_ord, rp.needed_counts, grid.num_chunks, M)
-        chi_IT_IP = chi_IT_LP.compose(chi_IP_LP.invert(allow_partial=True))
+        with span("ckpt.load.sf"):
+            chi_IP_LP = StarForest.from_flat_global_numbers(
+                locG, en, grid.num_chunks, M)
+            # (2.17): χ_{I_T}^{I_P}
+            chi_IT_LP = StarForest.from_flat_global_numbers(
+                rp.needed_ord, rp.needed_counts, grid.num_chunks, M)
+            chi_IT_IP = chi_IT_LP.compose(
+                chi_IP_LP.invert(allow_partial=True))
 
         # (2.18): broadcast OFF (and DOF, for validation) — flat leaf buffers
-        OFF_T = chi_IT_IP.bcast(locOFF, return_flat=True)
-        DOF_T = chi_IT_IP.bcast(locDOF, return_flat=True)
-        want = grid.chunk_sizes(rp.needed_ord)
-        if not np.array_equal(DOF_T, want):
-            nbad = int((DOF_T != want).sum())
-            raise ValueError(
-                f"{name}: saved chunk sizes disagree with layout for "
-                f"{nbad} of {len(want)} needed chunks")
-
-        # (2.22–2.23): element-level global ids for every target element
-        dof_ids_flat = (np.repeat(OFF_T[rp.inter_pos], rp.inter_sizes)
-                        + rp.elem_within)
+        with span("ckpt.load.bcast") as sp:
+            OFF_T = chi_IT_IP.bcast(locOFF, return_flat=True)
+            DOF_T = chi_IT_IP.bcast(locDOF, return_flat=True)
+            sp.attrs["bytes"] = OFF_T.nbytes + DOF_T.nbytes
+        with span("ckpt.load.plan"):
+            want = grid.chunk_sizes(rp.needed_ord)
+            if not np.array_equal(DOF_T, want):
+                nbad = int((DOF_T != want).sum())
+                raise ValueError(
+                    f"{name}: saved chunk sizes disagree with layout for "
+                    f"{nbad} of {len(want)} needed chunks")
+            # (2.22–2.23): element-level global ids for every target element
+            dof_ids_flat = (np.repeat(OFF_T[rp.inter_pos], rp.inter_sizes)
+                            + rp.elem_within)
 
         # (2.24): broadcast the vec through χ_{J_T}^{J_P}
-        chi_JT_JP = StarForest.from_flat_global_numbers(
-            dof_ids_flat, rp.elem_counts, D, M)
+        with span("ckpt.load.sf"):
+            chi_JT_JP = StarForest.from_flat_global_numbers(
+                dof_ids_flat, rp.elem_counts, D, M)
         locVEC = st.read_rows(vec, 0, D)   # canonical segments tile [0, D)
-        vec_flat = chi_JT_JP.bcast(locVEC, return_flat=True)
+        with span("ckpt.load.bcast") as sp:
+            vec_flat = chi_JT_JP.bcast(locVEC, return_flat=True)
+            sp.attrs["bytes"] = vec_flat.nbytes
 
         # scatter into the target region arrays (per-box reshaped views)
-        return rp.scatter_to_boxes(vec_flat, np_dtype(spec.dtype))
+        with span("ckpt.load.scatter"):
+            return rp.scatter_to_boxes(vec_flat, np_dtype(spec.dtype))
 
     # ------------------------------------------------------------- integrity
     @hot_path

@@ -80,6 +80,7 @@ from repro.core.star_forest import (
     partition_segments,
     partition_starts,
 )
+from repro.core.spans import span
 from repro.core.store import DEFAULT_SERIES, DatasetStore
 from repro.fem.element import Element
 from repro.fem.function import Function
@@ -645,197 +646,238 @@ class FEMCheckpoint:
     def load_mesh(self, name: str, comm: Comm, *, partition: str = "contiguous",
                   seed: int = 0, overlap: int = 1,
                   exact_distribution: bool = False) -> LoadedMesh:
-        st, M = self.store, comm.nranks
-        log = self._commit_log()
-        if log is not None and not any(
-                e.get("kind") == "mesh" and e.get("mesh") == name
-                for e in log):
-            raise ValueError(
-                f"load_mesh: mesh '{name}' has no entry in the async commit "
-                f"log — its save was interrupted before the commit marker; "
-                f"the torn datasets are not loadable")
-        meta = st.get_attrs(f"{name}/meta")
-        E, dim, gdim = meta["E"], meta["dim"], meta["gdim"]
-        starts = partition_starts(E, M)
-
-        # ---- Step 1 (DMPlexTopologyLoad): naive canonical partition → T00 --
-        chunks = split_segments(np.arange(E, dtype=_INT), np.diff(starts))
-        f00 = self._close_forest(name, chunks, E)
-        # T00 bookkeeping, flat: a position is "in chunk" iff its global id
-        # falls in its own rank's canonical range
-        in_chunk = ((f00.ids >= starts[f00.rank_rep])
-                    & (f00.ids < starts[f00.rank_rep + 1]))
-        cell_mask = in_chunk & (f00.dims == dim)
-        cells_flat = f00.ids[cell_mask]
-        cell_rank = f00.rank_rep[cell_mask]
-        cell_counts = np.bincount(cell_rank, minlength=M)
-        t00_cells = split_segments(cells_flat, cell_counts)
-        # T00 local numbering: canonical chunk first (ascending), then ghosts
-        order00 = np.lexsort((f00.ids, ~in_chunk, f00.rank_rep))
-        t00_counts = f00.counts
-        t00_locg_flat = f00.ids[order00]
-        chi_T00_LP = StarForest.from_flat_global_numbers(
-            t00_locg_flat, t00_counts, E, M)
-
-        # ---- Step 2 (DMPlexDistribute): repartition cells → T0 -------------
-        cell_bases = comm.exscan_sum([int(c) for c in cell_counts])
-        ncells = (cell_bases[-1] + int(cell_counts[-1])) if M else 0
-        if exact_distribution:
-            nsaved = meta["nranks_saved"]
-            if M != nsaved:
+        """Load mesh ``name`` onto ``comm.nranks`` ranks.  Its phases are
+        spans under ``fe.load_mesh``: the three closures (``fe.close``),
+        the cell repartition (``fe.partition``), owner resolution and
+        overlap growth (``fe.owners``), the local build
+        (``fe.build_locals``), the star forests that map the result back
+        to the saved numbering (``fe.directory``) and the coordinates
+        (``fe.coords``)."""
+        with span("fe.load_mesh"):
+            st, M = self.store, comm.nranks
+            log = self._commit_log()
+            if log is not None and not any(
+                    e.get("kind") == "mesh" and e.get("mesh") == name
+                    for e in log):
                 raise ValueError(
-                    f"exact-distribution reload needs the loading rank count "
-                    f"to equal the saving one: loading on M={M} ranks, "
-                    f"saved from N={nsaved}")
-            owner_rows = st.read_plan(f"{name}/topology/entity_owner",
-                                      *partition_segments(E, M))
-            # rank-major concatenation of the canonical segments == the full
-            # entity_owner table, indexable by global id (BSP-sim shortcut
-            # for the per-rank chunk lookups)
-            dests = np.concatenate(owner_rows)[cells_flat].astype(_INT)
-        elif partition == "contiguous":
-            # rank-major flat cell list == ascending global cell index
-            dests = partition_rank_of(np.arange(ncells, dtype=_INT),
-                                      ncells, M)
-        elif partition == "random":
-            dests = random_partition_dests(cells_flat, M, seed)
-        else:
-            raise ValueError(partition)
-        # CSR-pack by (source rank, destination) and ship the sparse edges —
-        # no dense R×R count matrix is ever materialised
-        sorder, sek_src, sek_dst, secnt = edge_pack(cell_rank, dests, M)
-        recv_flat, recv_offs = comm.neighbor_alltoallv(
-            sek_src, sek_dst, secnt, cells_flat[sorder], return_flat=True)
-        t0_cell_counts = np.diff(recv_offs)
-        recv_rank = np.repeat(np.arange(M, dtype=_INT), t0_cell_counts)
-        t0_cells = split_segments(recv_flat[np.lexsort((recv_flat,
-                                                        recv_rank))],
-                                  t0_cell_counts)
+                    f"load_mesh: mesh '{name}' has no entry in the async "
+                    f"commit log — its save was interrupted before the "
+                    f"commit marker; the torn datasets are not loadable")
+            meta = st.get_attrs(f"{name}/meta")
+            E, dim, gdim = meta["E"], meta["dim"], meta["gdim"]
+            starts = partition_starts(E, M)
 
-        f0 = self._close_forest(name, t0_cells, E)
-        # order T0 local numbering like the final rule for determinism
-        order0 = np.lexsort((f0.ids, -f0.dims, f0.rank_rep))
-        t0_locg_flat = f0.ids[order0]
-        t0_counts = f0.counts
-        t0_locg = f0.split(t0_locg_flat)
-        t0_owner = _resolve_owners(comm, E, t0_locg_flat, t0_counts,
-                                   t0_cells, f0)
-        # χ_{I_T0}^{I_T00}: root = T00 copy on the canonical rank of g
-        rr_flat = partition_rank_of(t0_locg_flat, E, M)
-        ri_flat = t0_locg_flat - starts[rr_flat]
-        chi_T0_T00 = StarForest(tuple(int(c) for c in t00_counts),
-                                tuple(f0.split(rr_flat)),
-                                tuple(f0.split(ri_flat)))
+            # ---- Step 1 (DMPlexTopologyLoad): canonical partition → T00 --
+            chunks = split_segments(np.arange(E, dtype=_INT),
+                                    np.diff(starts))
+            with span("fe.close"):
+                f00 = self._close_forest(name, chunks, E)
+            with span("fe.partition"):
+                # T00 bookkeeping, flat: a position is "in chunk" iff its
+                # global id falls in its own rank's canonical range
+                in_chunk = ((f00.ids >= starts[f00.rank_rep])
+                            & (f00.ids < starts[f00.rank_rep + 1]))
+                cell_mask = in_chunk & (f00.dims == dim)
+                cells_flat = f00.ids[cell_mask]
+                cell_rank = f00.rank_rep[cell_mask]
+                cell_counts = np.bincount(cell_rank, minlength=M)
+                t00_cells = split_segments(cells_flat, cell_counts)
+            with span("fe.directory"):
+                # T00 local numbering: canonical chunk first (ascending),
+                # then ghosts
+                order00 = np.lexsort((f00.ids, ~in_chunk, f00.rank_rep))
+                t00_counts = f00.counts
+                t00_locg_flat = f00.ids[order00]
+                chi_T00_LP = StarForest.from_flat_global_numbers(
+                    t00_locg_flat, t00_counts, E, M)
 
-        # ---- Step 3 (DMPlexDistributeOverlap): grow overlap → T ------------
-        final_cells = t0_cells
-        if overlap:
-            final_cells = _grow_overlap(comm, E, t0_cells, f0, overlap)
-        f_t = self._close_forest(name, final_cells, E)
-        t_owner = _resolve_owners(comm, E, f_t.ids, f_t.counts,
-                                  t0_cells, f_t)
-        # owner arrays are aligned to the forest's sorted ids; the batched
-        # local build carries them through its permutation
-        plexes = self._build_locals(f_t, dim, gdim,
-                                    owner_cat=np.concatenate(t_owner)
-                                    if f_t.n else None)
+            # ---- Step 2 (DMPlexDistribute): repartition cells → T0 ---------
+            with span("fe.partition"):
+                cell_bases = comm.exscan_sum([int(c) for c in cell_counts])
+                ncells = (cell_bases[-1] + int(cell_counts[-1])) if M else 0
+                if exact_distribution:
+                    nsaved = meta["nranks_saved"]
+                    if M != nsaved:
+                        raise ValueError(
+                            f"exact-distribution reload needs the loading "
+                            f"rank count to equal the saving one: loading on "
+                            f"M={M} ranks, saved from N={nsaved}")
+                    owner_rows = st.read_plan(
+                        f"{name}/topology/entity_owner",
+                        *partition_segments(E, M))
+                    # rank-major concatenation of the canonical segments ==
+                    # the full entity_owner table, indexable by global id
+                    # (BSP-sim shortcut for the per-rank chunk lookups)
+                    dests = np.concatenate(owner_rows)[cells_flat].astype(
+                        _INT)
+                elif partition == "contiguous":
+                    # rank-major flat cell list == ascending global cell index
+                    dests = partition_rank_of(np.arange(ncells, dtype=_INT),
+                                              ncells, M)
+                elif partition == "random":
+                    dests = random_partition_dests(cells_flat, M, seed)
+                else:
+                    raise ValueError(partition)
+                # CSR-pack by (source rank, destination) and ship the sparse
+                # edges — no dense R×R count matrix is ever materialised
+                sorder, sek_src, sek_dst, secnt = edge_pack(cell_rank, dests,
+                                                            M)
+                recv_flat, recv_offs = comm.neighbor_alltoallv(
+                    sek_src, sek_dst, secnt, cells_flat[sorder],
+                    return_flat=True)
+                t0_cell_counts = np.diff(recv_offs)
+                recv_rank = np.repeat(np.arange(M, dtype=_INT),
+                                      t0_cell_counts)
+                t0_cells = split_segments(recv_flat[np.lexsort((recv_flat,
+                                                                recv_rank))],
+                                          t0_cell_counts)
 
-        # χ_{I_T}^{I_T0}: directory over T0, queried with final LocG ---------
-        t0_owner_flat = np.concatenate(t0_owner) if f0.n else np.empty(0, _INT)
-        t0_owned = f0.split(t0_owner_flat
-                            == np.repeat(np.arange(M, dtype=_INT), t0_counts))
-        t0_dir = location_directory(t0_locg, t0_owned, E, comm)
-        chi_T_T0 = location_query(t0_dir, [lp.loc_g for lp in plexes], E, comm,
-                                  [len(g) for g in t0_locg])
+            with span("fe.close"):
+                f0 = self._close_forest(name, t0_cells, E)
+            with span("fe.owners"):
+                # order T0 local numbering like the final rule for determinism
+                order0 = np.lexsort((f0.ids, -f0.dims, f0.rank_rep))
+                t0_locg_flat = f0.ids[order0]
+                t0_counts = f0.counts
+                t0_locg = f0.split(t0_locg_flat)
+                t0_owner = _resolve_owners(comm, E, t0_locg_flat, t0_counts,
+                                           t0_cells, f0)
+            with span("fe.directory"):
+                # χ_{I_T0}^{I_T00}: root = T00 copy on the canonical rank of g
+                rr_flat = partition_rank_of(t0_locg_flat, E, M)
+                ri_flat = t0_locg_flat - starts[rr_flat]
+                chi_T0_T00 = StarForest(tuple(int(c) for c in t00_counts),
+                                        tuple(f0.split(rr_flat)),
+                                        tuple(f0.split(ri_flat)))
 
-        # ---- compose (B.4) --------------------------------------------------
-        chi_IT_LP = chi_T_T0.compose(chi_T0_T00.compose(chi_T00_LP))
+            # ---- Step 3 (DMPlexDistributeOverlap): grow overlap → T --------
+            final_cells = t0_cells
+            if overlap:
+                with span("fe.owners"):
+                    final_cells = _grow_overlap(comm, E, t0_cells, f0,
+                                                overlap)
+            with span("fe.close"):
+                f_t = self._close_forest(name, final_cells, E)
+            with span("fe.owners"):
+                t_owner = _resolve_owners(comm, E, f_t.ids, f_t.counts,
+                                          t0_cells, f_t)
+            with span("fe.build_locals"):
+                # owner arrays are aligned to the forest's sorted ids; the
+                # batched local build carries them through its permutation
+                plexes = self._build_locals(f_t, dim, gdim,
+                                            owner_cat=np.concatenate(t_owner)
+                                            if f_t.n else None)
 
-        point_sf = location_query(
-            location_directory([lp.loc_g for lp in plexes],
-                               [lp.owned for lp in plexes], E, comm),
-            [lp.loc_g for lp in plexes], E, comm,
-            [lp.num_entities for lp in plexes])
+            with span("fe.directory", directories=2, queries=2, composes=2):
+                # χ_{I_T}^{I_T0}: directory over T0, queried with final LocG
+                t0_owner_flat = (np.concatenate(t0_owner) if f0.n
+                                 else np.empty(0, _INT))
+                t0_owned = f0.split(t0_owner_flat
+                                    == np.repeat(np.arange(M, dtype=_INT),
+                                                 t0_counts))
+                t0_dir = location_directory(t0_locg, t0_owned, E, comm)
+                chi_T_T0 = location_query(
+                    t0_dir, [lp.loc_g for lp in plexes], E, comm,
+                    [len(g) for g in t0_locg])
 
-        # ---- labels ---------------------------------------------------------
-        labels = {}
-        for lname in meta.get("labels", []):
-            lchunks = st.read_plan(f"{name}/labels/{lname}",
-                                   *partition_segments(E, M))
-            labels[lname] = chi_IT_LP.bcast(lchunks)
+                # ---- compose (B.4) -----------------------------------------
+                chi_IT_LP = chi_T_T0.compose(chi_T0_T00.compose(chi_T00_LP))
 
-        mesh = LoadedMesh(plexes, chi_IT_LP, point_sf, E, dim, name, labels)
+                point_sf = location_query(
+                    location_directory([lp.loc_g for lp in plexes],
+                                       [lp.owned for lp in plexes], E, comm),
+                    [lp.loc_g for lp in plexes], E, comm,
+                    [lp.num_entities for lp in plexes])
 
-        # ---- coordinates (a P1 function, loaded like any function) ---------
-        if st.has_attrs(f"{name}/func/__coordinates/meta"):
-            spaces, funcs = self.load_function(mesh, "__coordinates", comm)
-            for lp, sp, f in zip(plexes, spaces, funcs):
-                vm = np.flatnonzero(lp.dims == 0)
-                lp.vcoords[vm] = f.values[sp.loc_off[vm][:, None]
-                                          + np.arange(sp.bs)]
-        return mesh
+            # ---- labels -----------------------------------------------------
+            labels = {}
+            for lname in meta.get("labels", []):
+                lchunks = st.read_plan(f"{name}/labels/{lname}",
+                                       *partition_segments(E, M))
+                labels[lname] = chi_IT_LP.bcast(lchunks)
+
+            mesh = LoadedMesh(plexes, chi_IT_LP, point_sf, E, dim, name,
+                              labels)
+
+            # ---- coordinates (a P1 function, loaded like any function) -----
+            if st.has_attrs(f"{name}/func/__coordinates/meta"):
+                with span("fe.coords"):
+                    spaces, funcs = self.load_function(mesh, "__coordinates",
+                                                       comm)
+                    for lp, sp, f in zip(plexes, spaces, funcs):
+                        vm = np.flatnonzero(lp.dims == 0)
+                        lp.vcoords[vm] = f.values[sp.loc_off[vm][:, None]
+                                                  + np.arange(sp.bs)]
+            return mesh
 
     # --------------------------------------------------------- load function
     @hot_path
     def load_function(self, mesh: LoadedMesh, fname: str, comm: Comm,
                       time_index: int | None = None
                       ) -> tuple[list[FunctionSpace], list[Function]]:
-        st, M = self.store, comm.nranks
-        # coordinates ride on the mesh's own commit entry (load_mesh checks)
-        log = self._commit_log()
-        if log is not None and fname != "__coordinates":
-            committed = [e.get("step") for e in log
-                         if e.get("kind") == "func"
-                         and e.get("mesh") == mesh.name
-                         and e.get("fname") == fname]
-            if time_index not in committed:
-                raise ValueError(
-                    f"load_function: '{fname}' time_index {time_index} is "
-                    f"not committed (committed: {sorted(s for s in committed if s is not None)}) "
-                    f"— a crash mid-write leaves the torn save invisible")
-        fmeta = st.get_attrs(f"{mesh.name}/func/{fname}/meta")
-        key = fmeta["section"]
-        smeta = st.get_attrs(f"{key}/meta")
-        D, Eo = smeta["D"], smeta["Eo"]
-        element = Element(smeta["family"], smeta["degree"], smeta["cell"])
-        bs = smeta["bs"]
-        E = mesh.E
+        with span("fe.load_function"):
+            st, M = self.store, comm.nranks
+            # coordinates ride on the mesh's own commit entry (load_mesh checks)
+            log = self._commit_log()
+            if log is not None and fname != "__coordinates":
+                committed = [e.get("step") for e in log
+                             if e.get("kind") == "func"
+                             and e.get("mesh") == mesh.name
+                             and e.get("fname") == fname]
+                if time_index not in committed:
+                    raise ValueError(
+                        f"load_function: '{fname}' time_index {time_index} is "
+                        f"not committed (committed: "
+                        f"{sorted(s for s in committed if s is not None)}) "
+                        f"— a crash mid-write leaves the torn save invisible")
+            fmeta = st.get_attrs(f"{mesh.name}/func/{fname}/meta")
+            key = fmeta["section"]
+            smeta = st.get_attrs(f"{key}/meta")
+            D, Eo = smeta["D"], smeta["Eo"]
+            element = Element(smeta["family"], smeta["degree"], smeta["cell"])
+            bs = smeta["bs"]
+            E = mesh.E
 
-        spaces = [FunctionSpace(lp, element, bs=bs) for lp in mesh.plexes]
+            spaces = [FunctionSpace(lp, element, bs=bs) for lp in mesh.plexes]
 
-        # ---- §2.2.5: load section chunks, build χ_{I_P}^{L_P} --------------
-        ea, en = partition_segments(Eo, M)
-        locG_P = [a.astype(_INT) for a in st.read_plan(f"{key}/G", ea, en)]
-        locDOF_P = [a.astype(_INT) for a in st.read_plan(f"{key}/DOF", ea, en)]
-        locOFF_P = [a.astype(_INT) for a in st.read_plan(f"{key}/OFF", ea, en)]
-        chi_IP_LP = chi_to_LP(locG_P, E)
+            # ---- §2.2.5: load section chunks, build χ_{I_P}^{L_P} ----------
+            ea, en = partition_segments(Eo, M)
+            locG_P = [a.astype(_INT)
+                      for a in st.read_plan(f"{key}/G", ea, en)]
+            locDOF_P = [a.astype(_INT)
+                        for a in st.read_plan(f"{key}/DOF", ea, en)]
+            locOFF_P = [a.astype(_INT)
+                        for a in st.read_plan(f"{key}/OFF", ea, en)]
+            chi_IP_LP = chi_to_LP(locG_P, E)
 
-        # ---- (2.17): χ_{I_T}^{I_P} = (χ_{I_P}^{L_P})⁻¹ ∘ χ_{I_T}^{L_P} ------
-        chi_IT_IP = mesh.chi_IT_LP.compose(chi_IP_LP.invert(allow_partial=True))
+            # ---- (2.17): χ_{I_T}^{I_P} = (χ_{I_P}^{L_P})⁻¹ ∘ χ_{I_T}^{L_P} --
+            chi_IT_IP = mesh.chi_IT_LP.compose(
+                chi_IP_LP.invert(allow_partial=True))
 
-        # ---- (2.18): broadcast DOF and OFF onto the loaded topology --------
-        DOF_T = chi_IT_IP.bcast(locDOF_P)
-        OFFg_T = chi_IT_IP.bcast(locOFF_P)
-        for sp, dof in zip(spaces, DOF_T):
-            if not np.array_equal(dof, sp.loc_dof):
-                raise ValueError(
-                    f"section/element mismatch between saved and loaded "
-                    f"space for '{fname}': saved per-entity DoF counts "
-                    f"disagree with {sp.element.family}{sp.element.degree} "
-                    f"bs={sp.bs}")
+            # ---- (2.18): broadcast DOF and OFF onto the loaded topology ----
+            DOF_T = chi_IT_IP.bcast(locDOF_P)
+            OFFg_T = chi_IT_IP.bcast(locOFF_P)
+            for sp, dof in zip(spaces, DOF_T):
+                if not np.array_equal(dof, sp.loc_dof):
+                    raise ValueError(
+                        f"section/element mismatch between saved and loaded "
+                        f"space for '{fname}': saved per-entity DoF counts "
+                        f"disagree with "
+                        f"{sp.element.family}{sp.element.degree} "
+                        f"bs={sp.bs}")
 
-        # ---- (2.22–2.23): lift to DoF level — one ragged_arange per rank ---
-        dof_globals = [ragged_arange(offg, sp.loc_dof)
-                       for sp, offg in zip(spaces, OFFg_T)]
-        chi_JT_JP = StarForest.from_global_numbers(dof_globals, D, M)
+            # ---- (2.22–2.23): lift to DoF level, one ragged_arange a rank --
+            dof_globals = [ragged_arange(offg, sp.loc_dof)
+                           for sp, offg in zip(spaces, OFFg_T)]
+            chi_JT_JP = StarForest.from_global_numbers(dof_globals, D, M)
 
-        # ---- (2.24): broadcast the vector ----------------------------------
-        suffix = "" if time_index is None else f"_t{time_index}"
-        locVEC_P = st.read_plan(f"{mesh.name}/func/{fname}/vec{suffix}",
-                                *partition_segments(D, M))
-        VEC_T = chi_JT_JP.bcast(locVEC_P)
-        funcs = [Function(sp, v) for sp, v in zip(spaces, VEC_T)]
-        return spaces, funcs
+            # ---- (2.24): broadcast the vector ------------------------------
+            suffix = "" if time_index is None else f"_t{time_index}"
+            locVEC_P = st.read_plan(f"{mesh.name}/func/{fname}/vec{suffix}",
+                                    *partition_segments(D, M))
+            VEC_T = chi_JT_JP.bcast(locVEC_P)
+            funcs = [Function(sp, v) for sp, v in zip(spaces, VEC_T)]
+            return spaces, funcs
 
 
 # ============================================================ loader helpers

@@ -31,6 +31,7 @@ from repro.core.jax_io import (
     snapshot_jax,
     tree_names,
 )
+from repro.core.spans import span
 from repro.core.store import DatasetStore
 from repro.core.tensor_ckpt import TensorCheckpoint
 from repro.train.data import SyntheticLM
@@ -111,21 +112,26 @@ class Trainer:
         entry is the commit marker, so a crash mid-write falls back to the
         previous committed step, and unchanged arrays dedup against the
         stream (stored once, aliased in the manifest)."""
-        ck = self._open_ckpt("a" if self._ckpt_exists() else "w")
-        if not ck.store.has_attrs("layout"):
-            ck.save_layout(layout_from_jax(state),
-                           extra={"pipeline": self.data.state(step_idx)})
-        if not self.cfg.async_ckpt:
-            ck.store.begin_step(step_idx)
-            save_jax(ck, state, step_idx)
-            ck.store.commit_step()
-            return
-        if self._async is None or self._async.ckpt.store.root != ck.store.root:
-            self._async = AsyncCheckpointer(ck, self.comm)
-        per_rank = snapshot_jax(ck.layout(), state)
-        self._async.begin_step(step_idx)
-        self._async.submit(per_rank, step_idx)
-        self._async.commit_step()
+        with span("ckpt.save", step=int(step_idx)):
+            ck = self._open_ckpt("a" if self._ckpt_exists() else "w")
+            if not ck.store.has_attrs("layout"):
+                ck.save_layout(layout_from_jax(state),
+                               extra={"pipeline": self.data.state(step_idx)})
+            if not self.cfg.async_ckpt:
+                ck.store.begin_step(step_idx)
+                save_jax(ck, state, step_idx)
+                ck.store.commit_step()
+                return
+            if (self._async is None
+                    or self._async.ckpt.store.root != ck.store.root):
+                self._async = AsyncCheckpointer(ck, self.comm)
+            per_rank = snapshot_jax(ck.layout(), state)
+            self._async.begin_step(step_idx)
+            self._async.submit(per_rank, step_idx)
+            self._async.commit_step()
+            # the arena holds the copy now: the loop also waits while the
+            # snapshot's chunk copies are freed, so that is inside the span
+            del per_rank
 
     def wait_for_writes(self) -> None:
         if self._async is not None:
