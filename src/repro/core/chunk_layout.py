@@ -180,8 +180,10 @@ class ChunkGrid:
         chunk ordinal) order — the row-per-intersection table the flat
         resharders walk instead of per-rank ``chunks_intersecting`` loops.
 
-        Returns ``(box_row, ordinal, inter_start, inter_stop, chunk_start)``
-        with the bound arrays ``[n_inter, ndim]``."""
+        Returns ``(box_row, ordinal, inter_start, inter_stop, chunk_start,
+        box_grid)`` with the bound arrays ``[n_inter, ndim]`` and
+        ``box_grid`` ``[nbox, ndim]``, the chunks each box spans per dim
+        (zero for a zero-volume box)."""
         box_starts = np.asarray(box_starts, dtype=_INT)
         box_stops = np.asarray(box_stops, dtype=_INT)
         nbox, nd = box_starts.shape
@@ -212,93 +214,74 @@ class ChunkGrid:
         cstop = np.minimum(cstart + cs, np.asarray(self.shape, dtype=_INT))
         istart = np.maximum(box_starts[rep], cstart)
         istop = np.minimum(box_stops[rep], cstop)
-        return rep, ords, istart, istop, cstart
+        return rep, ords, istart, istop, cstart, len_d
 
 
-@hot_path
-def box_element_positions(inner_start: np.ndarray, inner_stop: np.ndarray,
-                          outers: Sequence[tuple[np.ndarray, np.ndarray]]
-                          ) -> tuple[np.ndarray, list[np.ndarray]]:
-    """Row-major linear positions of every element of every inner box,
-    within one or more outer frames, flattened in (inner box, row-major)
-    order — the vectorised form of per-box :func:`row_major_ids` calls.
-
-    ``inner_start``/``inner_stop`` are ``[n, ndim]``; each outer frame is an
-    ``(outer_start [n, ndim], outer_shape [n, ndim])`` pair aligned to the
-    inner boxes.  Returns ``(box_row, [lin per frame])`` — computing every
-    frame in the same pass shares the one mixed-radix coordinate decode."""
-    inner_start = np.asarray(inner_start, dtype=_INT)
-    inner_stop = np.asarray(inner_stop, dtype=_INT)
-    n, nd = inner_start.shape
-    shape = inner_stop - inner_start
-    sizes = np.prod(shape, axis=1, dtype=_INT)
-    rep = np.repeat(np.arange(n, dtype=_INT), sizes)
-    j = np.arange(len(rep), dtype=_INT) - np.repeat(
-        np.cumsum(sizes) - sizes, sizes)
-    if nd == 1:
-        # 1-D fast path: the within-box coordinate IS ``j`` — skip the
-        # mixed-radix decode entirely (flat tensor state is the common case)
-        return rep, [
-            j + np.repeat(inner_start[:, 0]
-                          - np.asarray(ostart, dtype=_INT)[:, 0], sizes)
-            for ostart, _oshape in outers]
-    outs = [np.zeros(len(rep), dtype=_INT) for _ in outers]
-    strides = []
-    for ostart, oshape in outers:
-        st = np.ones((n, nd), dtype=_INT)
-        if nd > 1:
-            st[:, :-1] = np.cumprod(
-                np.asarray(oshape, dtype=_INT)[:, :0:-1], axis=1)[:, ::-1]
-        strides.append(st)
-    for d in reversed(range(nd)):
-        c = j % shape[rep, d]
-        j //= shape[rep, d]
-        for k, (ostart, _oshape) in enumerate(outers):
-            off = inner_start[rep, d] - np.asarray(ostart, dtype=_INT)[rep, d]
-            outs[k] += (off + c) * strides[k][rep, d]
-    return rep, outs
+def _nest(blocks: list, grid: Sequence[int]):
+    """Nest a row-major list of blocks into ``grid``-shaped lists, the
+    input :func:`numpy.block` takes (a 0-d grid is its one block)."""
+    for n in reversed(grid[1:]):
+        blocks = [blocks[i:i + n] for i in range(0, len(blocks), n)]
+    return blocks if len(grid) else blocks[0]
 
 
 @dataclasses.dataclass(frozen=True)
 class RegionPlan:
-    """Flat decomposition of per-rank target regions into chunk
-    intersections and elements — ONE rank-tagged table per phase instead of
-    nested ``for m in range(M): for box: for chunk`` Python walks (the
-    save-side counterpart of the loader's :class:`TopoForest` discipline).
+    """Flat decomposition of per-rank target regions into (box, chunk)
+    intersections — ONE rank-tagged row per intersection, never one per
+    element, instead of nested ``for m in range(M): for box: for chunk``
+    Python walks.
 
-    Enumeration order matches the historical per-rank walk exactly: boxes
-    rank-major in plan order, intersecting chunks ascending per box,
-    elements row-major per intersection — so star forests built from these
-    arrays are bit-identical to the per-rank formulation.
+    Enumeration order: boxes rank-major in plan order, intersecting chunks
+    ascending per box.  Each intersection is itself a box, so its elements
+    are a strided sub-block of its chunk's row-major run (the cone-derived
+    DoF order, §2.2), and the intersections of one box tile it as the
+    regular grid ``box_grid`` in that same order.
     """
 
     M: int
     box_rank: np.ndarray       # [nbox] target rank of each region box
     box_counts: np.ndarray     # [M] region boxes per rank
     box_shape: np.ndarray      # [nbox, nd]
-    box_sizes: np.ndarray      # [nbox] box volumes
+    box_grid: np.ndarray       # [nbox, nd] chunks each box spans per dim
     needed_ord: np.ndarray     # per-rank sorted unique chunk ordinals, flat
     needed_counts: np.ndarray  # [M]
     inter_box: np.ndarray      # [ni] box row of each (box, chunk) overlap
     inter_pos: np.ndarray      # [ni] position into needed_ord
+    inter_start: np.ndarray    # [ni, nd] overlap bounds, global coordinates
+    inter_stop: np.ndarray     # [ni, nd]
+    chunk_start: np.ndarray    # [ni, nd] bounds of the overlapped chunk
+    chunk_shape: np.ndarray    # [ni, nd]
     inter_sizes: np.ndarray    # [ni] overlap volumes
-    elem_within: np.ndarray    # [ne] row-major id within the owning chunk
-    elem_target: np.ndarray    # [ne] position into the concatenated boxes
-    elem_counts: np.ndarray    # [M] elements per rank
 
     @hot_path
-    def scatter_to_boxes(self, vals: np.ndarray, dtype) -> list[list[np.ndarray]]:
-        """Scatter per-element values (in plan enumeration order) into the
-        target boxes: one fancy assignment into the concatenated box buffer,
-        then per-box reshaped views grouped per rank — the shared epilogue
-        of the tensor loader and the in-memory resharder."""
-        out_flat = np.empty(int(self.box_sizes.sum()), dtype=dtype)
-        out_flat[self.elem_target] = vals
-        offs = np.concatenate([[0], np.cumsum(self.box_sizes)]).astype(_INT)
-        bufs = [out_flat[a:b].reshape(tuple(map(int, shp))) for a, b, shp in
-                zip(offs[:-1], offs[1:], self.box_shape)]
+    def fill_boxes(self, flat: np.ndarray, run_off: np.ndarray, dtype
+                   ) -> list[list[np.ndarray]]:
+        """Assemble the target boxes from the needed chunks' runs: needed
+        chunk ``p`` is ``flat[run_off[p]:]``, row-major over its chunk box.
+        Each intersection is a view of its run's sub-block and each box is
+        one :func:`numpy.block` over its intersections — strided block
+        copies, no per-element index array.  Returns fresh box arrays
+        grouped per rank — the shared epilogue of the tensor loader and
+        the in-memory resharder."""
+        a = np.asarray(run_off, dtype=_INT)[self.inter_pos]
+        b = a + np.prod(self.chunk_shape, axis=1, dtype=_INT)
+        lo = self.inter_start - self.chunk_start
+        hi = self.inter_stop - self.chunk_start
+        blocks = [flat[s:e].reshape(shp)[tuple(map(slice, l, h))]
+                  for s, e, shp, l, h in zip(
+                      a.tolist(), b.tolist(), self.chunk_shape.tolist(),
+                      lo.tolist(), hi.tolist())]
+        ib = np.concatenate(
+            [[0], np.cumsum(np.prod(self.box_grid, axis=1, dtype=_INT))]
+            ).astype(_INT).tolist()
+        bufs = [np.block(_nest(blocks[s:e], g)).astype(dtype, copy=False)
+                if e > s else np.empty(shp, dtype=dtype)
+                for s, e, g, shp in zip(ib[:-1], ib[1:],
+                                        self.box_grid.tolist(),
+                                        self.box_shape.tolist())]
         bb = np.concatenate([[0], np.cumsum(self.box_counts)]).astype(_INT)
-        return [bufs[a:b] for a, b in zip(bb[:-1], bb[1:])]
+        return [bufs[s:e] for s, e in zip(bb[:-1], bb[1:])]
 
 
 @hot_path
@@ -314,38 +297,34 @@ def plan_regions(grid: ChunkGrid, regions: Sequence[Sequence[Box]]
                       dtype=_INT).reshape(len(boxes), nd)
     bstop = np.array([b.stop for b in boxes],
                      dtype=_INT).reshape(len(boxes), nd)
-    ibox, iord, istart, istop, icstart = grid.intersections(bstart, bstop)
+    shape = np.asarray(grid.shape, dtype=_INT)
+    if (bstart < 0).any() or (bstop > shape).any():
+        raise ValueError(f"region boxes reach outside the array of shape "
+                         f"{grid.shape}")
+    ibox, iord, istart, istop, icstart, box_grid = grid.intersections(
+        bstart, bstop)
     # (rank, ordinal) packed needed-chunk keys — shared guarded radix
     radix = rank_radix(M, grid.num_chunks)
     key = box_rank[ibox] * radix + iord
     needed_key = np.unique(key)
     icstop = np.minimum(icstart + np.asarray(grid.chunk_shape, dtype=_INT),
-                        np.asarray(grid.shape, dtype=_INT))
-    _, (within, tlin) = box_element_positions(
-        istart, istop,
-        [(icstart, icstop - icstart), (bstart[ibox], bstop[ibox] - bstart[ibox])])
-    box_sizes = np.prod(bstop - bstart, axis=1, dtype=_INT)
-    box_base = (np.concatenate([[0], np.cumsum(box_sizes)])
-                if len(box_sizes) else np.zeros(1, _INT)).astype(_INT)
-    inter_sizes = np.prod(istop - istart, axis=1, dtype=_INT)
-    # element-level ranks/targets derive from the intersection table by
-    # repetition — never a per-element gather
+                        shape)
     return RegionPlan(
         M=M,
         box_rank=box_rank,
         box_counts=box_counts,
         box_shape=bstop - bstart,
-        box_sizes=box_sizes,
+        box_grid=box_grid,
         needed_ord=needed_key % radix,
         needed_counts=np.bincount(needed_key // radix, minlength=M
                                   ).astype(_INT),
         inter_box=ibox,
         inter_pos=np.searchsorted(needed_key, key).astype(_INT),
-        inter_sizes=inter_sizes,
-        elem_within=within,
-        elem_target=np.repeat(box_base[ibox], inter_sizes) + tlin,
-        elem_counts=np.bincount(box_rank[ibox], weights=inter_sizes,
-                                minlength=M).astype(_INT),
+        inter_start=istart,
+        inter_stop=istop,
+        chunk_start=icstart,
+        chunk_shape=icstop - icstart,
+        inter_sizes=np.prod(istop - istart, axis=1, dtype=_INT),
     )
 
 

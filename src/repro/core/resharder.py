@@ -4,17 +4,17 @@ replaced by live ranks (elastic scaling without touching disk).
 The composition is identical to the checkpoint loader, but the pivot directory
 is built over *entities* only (one (rank, base-offset) record per chunk, never
 per element): a target rank resolves each needed chunk to its source rank and
-the chunk's base position in the source's local DoF vector, then derives
-element-level roots locally from the within-box row-major order (cone-derived
-DoF order).  A single SF bcast then moves the data — one all-to-all, which is
-also the number PetscSFBcast would issue.
+the chunk's base position in the source's local DoF vector.  The chunk's DoFs
+are one run there, row-major in its box (cone-derived DoF order), so the data
+moves as one strided block copy per (target box, chunk) intersection — one
+exchange round, as PetscSFBcast would issue.
 
 Rank-flat: the target-side region walk is ONE :class:`RegionPlan` per array
-(the same flat (box, chunk, element) table the tensor checkpoint loader
+(the same flat (box, chunk) intersection table the tensor checkpoint loader
 uses) and the source-side chunk bases come from one vectorised cumsum over
 the rank-tagged size array — no ``for r in range(N)`` / ``for m in
-range(M)`` numpy work anywhere.  Star forests and CommStats are
-bit-identical to the per-rank formulation.
+range(M)`` numpy work anywhere.  CommStats count the intersections' bytes,
+as the per-element formulation did.
 """
 
 from __future__ import annotations
@@ -58,7 +58,6 @@ def reshard(layout: StateLayout, source: PerRankState,
                               weights=sizes, minlength=N).astype(_INT)
         src_flat = (np.concatenate(blocks) if blocks
                     else np.empty(0, np_dtype(spec.dtype)))
-        src_vecs = split_segments(src_flat, vec_cnt)
         # within-rank base of each chunk: global exclusive cumsum rebased to
         # the rank segment start
         cs = np.concatenate([[0], np.cumsum(sizes)]).astype(_INT)
@@ -85,20 +84,18 @@ def reshard(layout: StateLayout, source: PerRankState,
         got_rank = qry.bcast(dir_rank, return_flat=True)
         got_base = qry.bcast(dir_base, return_flat=True)
         comm_dst.stats.record(int(got_rank.nbytes) * 2, 0)
+        if (got_rank < 0).any():
+            raise ValueError(
+                f"{name}: {int((got_rank < 0).sum())} needed chunks are held "
+                f"by no source rank")
 
-        # element-level SF: target element -> (source rank, vec position),
-        # derived from the flat intersection table in one repeat + add
-        rr_flat = np.repeat(got_rank[rp.inter_pos], rp.inter_sizes)
-        ri_flat = (np.repeat(got_base[rp.inter_pos], rp.inter_sizes)
-                   + rp.elem_within)
-        # rectangular SF: M leaf ranks, N root ranks
-        sf = StarForest.from_flat_attachments(
-            [len(v) for v in src_vecs], rp.elem_counts, rr_flat, ri_flat)
-        vals = sf.bcast(src_vecs, return_flat=True)
-        comm_dst.stats.record(int(vals.nbytes), 0)
-
-        # scatter into the target boxes (per-box reshaped views, per rank)
-        per_rank_bufs = rp.scatter_to_boxes(vals, np_dtype(spec.dtype))
+        # each needed chunk is one run of its source rank's local vec: the
+        # block plan copies every (box, chunk) intersection out of it
+        vec_start = np.concatenate([[0], np.cumsum(vec_cnt)[:-1]]).astype(_INT)
+        per_rank_bufs = rp.fill_boxes(
+            src_flat, vec_start[got_rank] + got_base, np_dtype(spec.dtype))
+        comm_dst.stats.record(
+            int(rp.inter_sizes.sum()) * src_flat.itemsize, 0)
         for slot, regs, bufs in zip(out, regions, per_rank_bufs):
             if regs:
                 slot[name] = bufs

@@ -13,9 +13,12 @@ Save side (N ranks), per array (== per 'function space' of the paper):
 Load side (M ranks, arbitrary target regions — need not align with chunks):
   * read canonical section chunks -> χ_{I_P}^{L_P} (§2.2.5);
   * needed chunks -> χ_{I_T}^{I_P} = (χ_{I_P}^{L_P})⁻¹ ∘ χ_{I_T}^{L_P} (2.17);
-  * broadcast DOF/OFF (2.18); lift to element level via within-box row-major
-    positions (the cone-derived DoF order; 2.22–2.23);
-  * broadcast vec values from the canonical vec partition (2.24).
+  * broadcast DOF/OFF (2.18);
+  * move the vec (2.22–2.24): each needed chunk's DoFs are one contiguous
+    run vec[OFF : OFF + DOF], row-major in its box (the cone-derived DoF
+    order), so every (target region, chunk) intersection is a strided
+    sub-block of that run and the target regions are filled by block
+    copies planned per intersection, never per element.
 
 Same-count fast path: when the target regions are exactly the chunks a rank
 saved, its vec range is read back verbatim with zero index math (§3.1 end).
@@ -362,10 +365,10 @@ class TensorCheckpoint:
                 bb = np.concatenate([[0], np.cumsum(e_cnt)]).astype(_INT)
                 return [blocks[a:b] for a, b in zip(bb[:-1], bb[1:])]
 
-        # ---- general path: ONE flat region plan, no per-rank walks ---------
+        # ---- general path: ONE flat block plan, no per-rank walks ---------
         with span("ckpt.load.plan") as sp:
             rp = plan_regions(grid, regions)
-            sp.attrs["elements"] = int(rp.elem_within.size)
+            sp.attrs["elements"] = int(rp.inter_sizes.sum())
 
         # §2.2.5: canonical section chunks -> χ_{I_P}^{L_P}.  The canonical
         # segments tile [0, Eo), so one contiguous read IS the coalesced
@@ -395,22 +398,22 @@ class TensorCheckpoint:
                 raise ValueError(
                     f"{name}: saved chunk sizes disagree with layout for "
                     f"{nbad} of {len(want)} needed chunks")
-            # (2.22–2.23): element-level global ids for every target element
-            dof_ids_flat = (np.repeat(OFF_T[rp.inter_pos], rp.inter_sizes)
-                            + rp.elem_within)
+            if ((OFF_T < 0) | (OFF_T + DOF_T > D)).any():
+                raise ValueError(
+                    f"{name}: saved chunk offsets reach outside the "
+                    f"{D}-element vec")
 
-        # (2.24): broadcast the vec through χ_{J_T}^{J_P}
-        with span("ckpt.load.sf"):
-            chi_JT_JP = StarForest.from_flat_global_numbers(
-                dof_ids_flat, rp.elem_counts, D, M)
+        # (2.22–2.24): needed chunk p's DoFs are the run vec[OFF : OFF +
+        # DOF] of the canonical vec, row-major in its box (the cone-derived
+        # DoF order), so each (target box, chunk) intersection is a strided
+        # sub-block of that run: the element-level χ_{J_T}^{J_P} is carried
+        # by the intersection table, and the vec moves as block copies.
         locVEC = st.read_rows(vec, 0, D)   # canonical segments tile [0, D)
-        with span("ckpt.load.bcast") as sp:
-            vec_flat = chi_JT_JP.bcast(locVEC, return_flat=True)
-            sp.attrs["bytes"] = vec_flat.nbytes
-
-        # scatter into the target region arrays (per-box reshaped views)
-        with span("ckpt.load.scatter"):
-            return rp.scatter_to_boxes(vec_flat, np_dtype(spec.dtype))
+        with span("ckpt.load.scatter") as sp:
+            dtype = np_dtype(spec.dtype)
+            sp.attrs["blocks"] = len(rp.inter_box)
+            sp.attrs["bytes"] = int(rp.inter_sizes.sum()) * dtype.itemsize
+            return rp.fill_boxes(locVEC, OFF_T, dtype)
 
     # ------------------------------------------------------------- integrity
     @hot_path

@@ -262,12 +262,22 @@ def test_load_jax_one_to_one_records_the_engine_phases(tmp_path):
     assert top == {"ckpt.load.state": 1, "ckpt.load.h2d": 1}
     st, = [s for s in rec if s.name == "ckpt.load.state"]
     kids = _names(_children(rec, st))
-    # "w" (16 x 8, 16 chunks) takes the general path: the fast path's
-    # test, the region plan and the element lift are plan spans; "b" and
-    # "n" are one chunk each and read back on the same-count fast path
+    # "w" (16 x 8, one chunk per element) takes the general path: the fast
+    # path's test, the block plan and the chunk-size check are plan spans,
+    # the chunk-level forests one sf and one bcast span, and the block
+    # copies one scatter span; "b" and "n" are one chunk each and read back
+    # on the same-count fast path
     assert kids == {"ckpt.load.plan": 3 + 2, "ckpt.load.read": 4 + 2,
-                    "ckpt.load.sf": 2, "ckpt.load.bcast": 2,
+                    "ckpt.load.sf": 1, "ckpt.load.bcast": 1,
                     "ckpt.load.scatter": 1 + 2}
+    w_chunks = ck.layout().spec("w").grid.num_chunks
+    assert w_chunks > 1
+    plans = [s.attrs for s in _children(rec, st)
+             if s.name == "ckpt.load.plan" and "elements" in s.attrs]
+    assert plans == [{"elements": 16 * 8}]
+    blocks = [s.attrs for s in _children(rec, st)
+              if s.name == "ckpt.load.scatter" and s.attrs]
+    assert blocks == [{"blocks": w_chunks, "bytes": 16 * 8 * 4}]
 
 
 _FOUR_TO_TWO = """
@@ -298,6 +308,9 @@ st = [s for s in rec if s.name == "ckpt.load.state"][-1]
 print(json.dumps({
     "load": [s.attrs for s in rec if s.name == "ckpt.load"],
     "children": sorted(s.name for s in rec if s.parent_id == st.span_id),
+    "scatter": [s.attrs for s in rec if s.name == "ckpt.load.scatter"
+                and s.parent_id == st.span_id],
+    "blocks_expected": ck.layout().spec("x").grid.num_chunks,
     "shards": len(out.addressable_shards)}))
 """
 
@@ -315,8 +328,11 @@ def test_load_jax_four_devices_onto_two_records_the_engine_phases(tmp_path):
     assert got["load"] == [{"bytes": 64 * 6 * 4}]
     assert got["shards"] == 2
     assert collections.Counter(got["children"]) == {
-        "ckpt.load.plan": 3, "ckpt.load.read": 4, "ckpt.load.sf": 2,
-        "ckpt.load.bcast": 2, "ckpt.load.scatter": 1}
+        "ckpt.load.plan": 3, "ckpt.load.read": 4, "ckpt.load.sf": 1,
+        "ckpt.load.bcast": 1, "ckpt.load.scatter": 1}
+    # two target boxes of 32 rows, each cut from the saved chunk runs
+    assert got["scatter"] == [{"blocks": got["blocks_expected"],
+                               "bytes": 64 * 6 * 4}]
 
 
 # ---------------------------------------------------------- the FE restart
